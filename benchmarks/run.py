@@ -15,13 +15,15 @@
                     queries; posterior predictive as one jit(vmap) vs
                     the per-draw loop
   sharding        — mesh-dispatched chains (chain-throughput scaling on
-                    forced multi-device CPU, subprocess per device
-                    count) + tall-data weak scaling of the psum density
+                    1 and 4 devices of this process) + tall-data weak
+                    scaling of the psum density
 
 ``python -m benchmarks.run [--fast] [--only SECTION] [--chains N]
 [--json-dir DIR]`` (--fast cuts table1 to 200 iterations for quick
 regression runs; --json-dir additionally writes the schema-valid
-``BENCH_*.json`` reports — logjoint, leapfrog, roofline — into DIR)
+``BENCH_*.json`` reports — logjoint, leapfrog, roofline — into DIR).
+A failing section or report does not stop the others, but the command
+then exits 1.
 """
 from __future__ import annotations
 
@@ -29,6 +31,7 @@ import argparse
 import os
 import sys
 import time
+import traceback
 
 
 def run_multichain(num_chains: int, fast: bool = False):
@@ -67,6 +70,9 @@ def main(argv=None) -> int:
                         "(adds the 'multichain' section)")
     args = p.parse_args(argv)
 
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     sections = []
     if args.only in (None, "typed_ablation"):
         from benchmarks import typed_ablation
@@ -100,6 +106,7 @@ def main(argv=None) -> int:
         iters = 200 if args.fast else 2000
         sections.append(("table1", lambda: table1.run(iters=iters)))
 
+    failed = []
     for name, fn in sections:
         print(f"==== {name} ====", flush=True)
         t0 = time.time()
@@ -107,7 +114,9 @@ def main(argv=None) -> int:
             for line in fn():
                 print(line, flush=True)
         except Exception as e:  # keep the suite going; record the failure
+            traceback.print_exc()
             print(f"{name}/ERROR,0,{e!r}", flush=True)
+            failed.append(name)
         print(f"==== {name} done in {time.time() - t0:.0f}s ====", flush=True)
 
     if args.json_dir:
@@ -142,7 +151,12 @@ def main(argv=None) -> int:
                 write_report(reporter(), path)
                 print(f"wrote {path}", flush=True)
             except Exception as e:
+                traceback.print_exc()
                 print(f"JSON {fname} FAILED: {e!r}", flush=True)
+                failed.append(fname)
+    if failed:
+        print(f"FAILED: {', '.join(failed)}", flush=True)
+        return 1
     return 0
 
 

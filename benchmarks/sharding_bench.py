@@ -1,13 +1,14 @@
 """Sharded-inference bench: chain scaling + tall-data weak scaling.
 
-Mesh programs need ``XLA_FLAGS=--xla_force_host_platform_device_count=N``
-set BEFORE jax import, so every measured cell runs in a fresh
-subprocess with its own forced device count; this parent aggregates the
-cells into one schema-valid ``BENCH_sharding.json`` report.
+Every cell runs in this process on ``jax.devices()[:D]`` for D = 1 and
+4, and the cells are aggregated into one schema-valid
+``BENCH_sharding.json`` report. The process needs four devices: a
+four-chip host, or on the CPU
+``XLA_FLAGS=--xla_force_host_platform_device_count=4`` set before JAX
+starts (there "devices" are host threads of ONE machine — a
+correctness and compilation story, not a hardware-speed one).
 
-Two stories, both on forced multi-device CPU (where "devices" are
-host threads of ONE machine — a correctness and compilation story, not
-a hardware-speed one):
+Two stories:
 
 * ``chains`` — chain-throughput scaling. Forced CPU devices share the
   physical cores, so the honest headline is the PER-DEVICE projection:
@@ -25,9 +26,6 @@ a hardware-speed one):
 from __future__ import annotations
 
 import argparse
-import json
-import os
-import subprocess
 import sys
 from typing import Dict, List
 
@@ -36,38 +34,20 @@ WARMUP = 1
 REPEATS = 3
 
 
-def _child_env(num_devices: int) -> Dict[str, str]:
-    env = dict(os.environ)
-    kept = [t for t in env.get("XLA_FLAGS", "").split()
-            if not t.startswith("--xla_force_host_platform_device_count")]
-    env["XLA_FLAGS"] = " ".join(
-        [f"--xla_force_host_platform_device_count={num_devices}"] + kept)
-    env.setdefault("JAX_PLATFORMS", "cpu")
-    root = os.path.join(os.path.dirname(__file__), os.pardir)
-    src = os.path.abspath(os.path.join(root, "src"))
-    env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
-    return env
-
-
-def _run_child(cell: str, num_devices: int, fast: bool) -> Dict:
-    """One measurement cell in a subprocess; returns its JSON dict."""
-    code = subprocess.run(
-        [sys.executable, "-m", "benchmarks.sharding_bench",
-         "--child", cell, "--devices", str(num_devices)]
-        + (["--fast"] if fast else []),
-        env=_child_env(num_devices), capture_output=True, text=True,
-        cwd=os.path.abspath(os.path.join(os.path.dirname(__file__),
-                                         os.pardir)))
-    if code.returncode != 0:
+def _devices(num_devices: int) -> List:
+    import jax
+    devs = jax.devices()[:num_devices]
+    if len(devs) < num_devices:
         raise RuntimeError(
-            f"sharding bench cell {cell}@{num_devices}dev failed:\n"
-            f"{code.stdout}\n{code.stderr}")
-    # last line of stdout is the JSON payload (jax may log above it)
-    return json.loads(code.stdout.strip().splitlines()[-1])
+            f"the sharding bench needs {num_devices} devices and JAX has "
+            f"{len(devs)}; on the CPU set XLA_FLAGS="
+            f"--xla_force_host_platform_device_count={num_devices} before "
+            "JAX starts")
+    return devs
 
 
 # ---------------------------------------------------------------------------
-# child cells (run under a forced device count)
+# cells (each on the first ``num_devices`` devices)
 # ---------------------------------------------------------------------------
 def _time(fn, repeats: int = REPEATS, warmup: int = WARMUP) -> float:
     import time
@@ -97,7 +77,8 @@ def _chains_cell(num_devices: int, fast: bool) -> Dict:
     kernel = HMC(step_size=pm.step_size, n_leapfrog=4, adapt_step_size=True)
     key = jax.random.PRNGKey(SEED)
 
-    out = {"devices": jax.device_count(), "chains_total": chains_total,
+    devs = _devices(num_devices)
+    out = {"devices": num_devices, "chains_total": chains_total,
            "num_samples": num_samples, "num_warmup": num_warmup, "n_rows": n}
 
     def run(nc, mesh=None):
@@ -107,11 +88,11 @@ def _chains_cell(num_devices: int, fast: bool) -> Dict:
     # full fleet on one device (the single-device baseline program)
     out["wall_full_s"] = _time(lambda: run(chains_total))
     # the per-device slice: what ONE device of a D-device fleet executes
-    per_dev = max(1, chains_total // jax.device_count())
+    per_dev = max(1, chains_total // num_devices)
     clear_cache()
     out["wall_perdev_s"] = _time(lambda: run(per_dev))
-    if jax.device_count() > 1:
-        plan = ShardedRun.plan()
+    if num_devices > 1:
+        plan = ShardedRun.plan(devices=devs)
         clear_cache()
         out["wall_mesh_s"] = _time(lambda: run(chains_total, mesh=plan))
         ch = run(chains_total, mesh=plan)
@@ -136,7 +117,8 @@ def _weakdata_cell(num_devices: int, fast: bool) -> Dict:
         jax.random.PRNGKey(SEED), pm.model, kernel, num_chains=1,
         init_jitter=0.0)
     q = q0s[0]
-    out = {"devices": jax.device_count(), "rows": rows, "dim": dim}
+    devs = _devices(num_devices)
+    out = {"devices": num_devices, "rows": rows, "dim": dim}
 
     ld_full = pm.model.make_logdensity_fn(tvi)
     vg_full = jax.jit(jax.value_and_grad(ld_full))
@@ -153,8 +135,8 @@ def _weakdata_cell(num_devices: int, fast: bool) -> Dict:
     out["wall_pershard_s"] = _time(
         lambda: jax.block_until_ready(vg_shard(q)))
 
-    if jax.device_count() > 1:
-        plan = ShardedRun.plan(data_shards=jax.device_count(),
+    if num_devices > 1:
+        plan = ShardedRun.plan(data_shards=num_devices, devices=devs,
                                shard_sites=("y",))
         ld_mesh = make_sharded_logdensity(pm.model, tvi, plan)
         v_mesh = float(ld_mesh(q))
@@ -171,15 +153,15 @@ def _weakdata_cell(num_devices: int, fast: bool) -> Dict:
 
 
 # ---------------------------------------------------------------------------
-# parent: aggregate cells into the report
+# aggregate cells into the report
 # ---------------------------------------------------------------------------
 def report(fast: bool = False) -> Dict:
     from benchmarks.bench_io import entry, make_report
 
     entries: List[Dict] = []
 
-    c1 = _run_child("chains", 1, fast)
-    c4 = _run_child("chains", 4, fast)
+    c1 = _chains_cell(1, fast)
+    c4 = _chains_cell(4, fast)
     # per-device projection: T(all chains, 1 dev) / T(per-device slice)
     scaling = c1["wall_full_s"] / max(c4["wall_perdev_s"], 1e-9)
     draws = c1["chains_total"] * c1["num_samples"]
@@ -201,8 +183,8 @@ def report(fast: bool = False) -> Dict:
         wall_mesh_measured_s=round(c4.get("wall_mesh_s", 0.0), 4),
         mesh_cache_misses=c4.get("mesh_cache_misses", 0)))
 
-    w1 = _run_child("weakdata", 1, fast)
-    w4 = _run_child("weakdata", 4, fast)
+    w1 = _weakdata_cell(1, fast)
+    w4 = _weakdata_cell(4, fast)
     weak = w1["wall_full_s"] / max(w4["wall_pershard_s"], 1e-9)
     entries.append(entry(
         "sharding/weakdata_density_grad",
@@ -217,7 +199,7 @@ def report(fast: bool = False) -> Dict:
         grad_rel_err=w4.get("grad_rel_err", 0.0)))
 
     return make_report("sharding", entries, seed=SEED, warmup=WARMUP,
-                       repeats=REPEATS, backend="cpu")
+                       repeats=REPEATS)
 
 
 def run(fast: bool = False):
@@ -234,16 +216,7 @@ def main(argv=None) -> int:
     p = argparse.ArgumentParser()
     p.add_argument("--fast", action="store_true")
     p.add_argument("--json", default=None, metavar="PATH")
-    p.add_argument("--child", default=None,
-                   choices=("chains", "weakdata"), help=argparse.SUPPRESS)
-    p.add_argument("--devices", type=int, default=1, help=argparse.SUPPRESS)
     args = p.parse_args(argv)
-
-    if args.child:
-        cell = {"chains": _chains_cell,
-                "weakdata": _weakdata_cell}[args.child]
-        print(json.dumps(cell(args.devices, args.fast)))
-        return 0
 
     rep = report(fast=args.fast)
     for e in rep["entries"]:

@@ -47,10 +47,7 @@ from repro.core.varinfo import FlatLayout, TypedVarInfo, typify
 __all__ = ["GraphNode", "ModelGraph", "SiteRecord", "build_model_graph"]
 
 
-try:  # jaxpr Literal moved between jax versions
-    from jax.extend.core import Literal as _Literal
-except Exception:  # pragma: no cover - version fallback
-    from jax.core import Literal as _Literal
+from jax.extend.core import Literal as _Literal
 
 
 @dataclasses.dataclass

@@ -366,7 +366,6 @@ def _sharded_chain_outs(plan, model, tvi, kernel, dim: int, num_warmup: int,
     the fused density backend.
     """
     import jax
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.core.contexts import LikelihoodContext, PriorContext
@@ -422,11 +421,11 @@ def _sharded_chain_outs(plan, model, tvi, kernel, dim: int, num_warmup: int,
         body = _chain_body(kern, num_warmup, num_samples)
         return jax.vmap(body)(ckeys, local_q0s)
 
-    mapped = shard_map(
+    mapped = jax.shard_map(
         local_run, mesh=plan.mesh,
         in_specs=(P(plan.chain_axis), P(plan.chain_axis))
         + (P(plan.data_axis),) * len(sites),
-        out_specs=P(plan.chain_axis), check_rep=False)
+        out_specs=P(plan.chain_axis), check_vma=False)
 
     csh = plan.chain_sharding()
     chain_keys = jax.device_put(chain_keys, csh)

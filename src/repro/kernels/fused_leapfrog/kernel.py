@@ -30,7 +30,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.fused_leapfrog.spec import (potential_elem_grad,
                                                potential_elem_value)
-from repro.kernels.fused_logpdf.kernel import LANE, SUB, _CompilerParams
+from repro.kernels.fused_logpdf.kernel import LANE, SUB
 
 __all__ = ["leapfrog_2d", "potential_vg_2d", "LANE", "SUB"]
 
@@ -119,7 +119,7 @@ def leapfrog_2d(eps, q, p, g, op, c0, c1, c2, c3, im, n_steps: int,
             jax.ShapeDtypeStruct((1, 1), jnp.float32),
         ),
         scratch_shapes=[pltpu.VMEM((SUB, LANE), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="fused_leapfrog",
@@ -176,7 +176,7 @@ def potential_vg_2d(q, op, c0, c1, c2, c3, uniform_op, block_rows: int,
             jax.ShapeDtypeStruct((1, 1), jnp.float32),
         ),
         scratch_shapes=[pltpu.VMEM((SUB, LANE), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="fused_potential_vg",

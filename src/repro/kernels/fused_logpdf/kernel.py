@@ -28,10 +28,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 LANE = 128
 SUB = 8
 _HALF_LOG_2PI = 0.5 * math.log(2.0 * math.pi)
@@ -214,10 +210,12 @@ def _student_t_kernel(z_ref, df_ref, o_ref, acc_ref, *, n_valid: int):
 # Dense MvNormal quadratic form: xc (N, D) rows against one precision P
 # (D, D), flash-attention-style — the xc row-block stays VMEM-resident
 # while the grid streams P column-blocks through the MXU; only the scalar
-# leaves the kernel. Zero-padding of xc/P makes padded rows/cols contribute
-# exactly 0, so no masks are needed.
+# leaves the kernel. The matching xc column block arrives through its own
+# BlockSpec: Mosaic has no lowering for a dynamic slice of a loaded value.
+# Zero-padding of xc/P makes padded rows/cols contribute exactly 0, so no
+# masks are needed.
 # ---------------------------------------------------------------------------
-def _mvn_quad_kernel(x_ref, p_ref, o_ref, acc_ref, *, block_cols: int):
+def _mvn_quad_kernel(x_ref, xj_ref, p_ref, o_ref, acc_ref):
     i = pl.program_id(0)
     j = pl.program_id(1)
     ni = pl.num_programs(0)
@@ -230,9 +228,7 @@ def _mvn_quad_kernel(x_ref, p_ref, o_ref, acc_ref, *, block_cols: int):
     xc = x_ref[...].astype(jnp.float32)            # (bn, Dp) full rows
     pj = p_ref[...].astype(jnp.float32)            # (Dp, bc) column block
     t = jnp.dot(xc, pj, preferred_element_type=jnp.float32)  # (bn, bc) MXU
-    xcj = jax.lax.dynamic_slice(xc, (0, j * block_cols),
-                                (xc.shape[0], block_cols))
-    part = t * xcj                                  # (bn, bc)
+    part = t * xj_ref[...].astype(jnp.float32)      # (bn, bc)
     acc_ref[...] += jnp.sum(part.reshape(-1, SUB, LANE), axis=0)
 
     @pl.when((i == ni - 1) & (j == nj - 1))
@@ -256,7 +252,7 @@ def _reduce_call(kernel, n_inputs: int, rows: int, block_rows: int,
                                memory_space=pltpu.SMEM),
         out_shape=jax.ShapeDtypeStruct((1, 1), jnp.float32),
         scratch_shapes=[pltpu.VMEM(acc_shape, jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name=name,
@@ -321,23 +317,23 @@ def mvn_quad_sum_2d(xc, prec, block_rows: int, block_cols: int,
     """xc (Np, Dp), prec (Dp, Dp) — both zero-padded to tile multiples."""
     np_, dp = xc.shape
     grid = (np_ // block_rows, dp // block_cols)
-    kern = functools.partial(_mvn_quad_kernel, block_cols=block_cols)
     return pl.pallas_call(
-        kern,
+        _mvn_quad_kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_rows, dp), lambda i, j: (i, 0)),
+            pl.BlockSpec((block_rows, block_cols), lambda i, j: (i, j)),
             pl.BlockSpec((dp, block_cols), lambda i, j: (0, j)),
         ],
         out_specs=pl.BlockSpec((1, 1), lambda i, j: (0, 0),
                                memory_space=pltpu.SMEM),
         out_shape=jax.ShapeDtypeStruct((1, 1), jnp.float32),
         scratch_shapes=[pltpu.VMEM((SUB, LANE), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
         name="fused_mvn_quadform",
-    )(xc, prec)[0, 0]
+    )(xc, xc, prec)[0, 0]
 
 
 def categorical_sum_2d(logits, labels, n_valid: int, c_valid: int,
@@ -357,7 +353,7 @@ def categorical_sum_2d(logits, labels, n_valid: int, c_valid: int,
                                memory_space=pltpu.SMEM),
         out_shape=jax.ShapeDtypeStruct((1, 1), jnp.float32),
         scratch_shapes=[pltpu.VMEM((SUB, 1), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="fused_categorical_logpdf",

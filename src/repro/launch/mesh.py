@@ -23,12 +23,16 @@ MULTIPOD_SHAPE: Tuple[int, int, int] = (2, 16, 16)
 def make_production_mesh(*, multi_pod: bool = False):
     shape = MULTIPOD_SHAPE if multi_pod else POD_SHAPE
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
-    """Arbitrary mesh (elastic re-mesh path; see runtime.elastic)."""
-    return jax.make_mesh(shape, axes)
+    """Arbitrary mesh (elastic re-mesh path; see runtime.elastic).
+
+    Axes are ``Auto``: the model code places activations with
+    ``with_sharding_constraint``, which refuses ``Explicit`` axes."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 # trainable-parameter bytes/chip thresholds: Adam(f32 m+v) + bf16 param +

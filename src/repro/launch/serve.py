@@ -33,6 +33,7 @@ import numpy as np
 from repro import configs
 from repro.models import bayes_lm
 from repro.nn import lm
+from repro.runtime.compile_cache import enable_compile_cache
 
 
 def serve_batch(arch: str, *, smoke: bool = True, batch: int = 4,
@@ -263,6 +264,7 @@ def main(argv=None) -> int:
                    help="(--queries) number of demo requests")
     p.add_argument("--seed", type=int, default=0)
     args = p.parse_args(argv)
+    enable_compile_cache()
 
     if args.queries:
         stats = serve_queries(num_requests=args.requests,

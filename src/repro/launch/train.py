@@ -33,13 +33,14 @@ from repro.launch import mesh as mesh_lib
 from repro.models import bayes_lm
 from repro.nn import lm
 from repro.runtime import PreemptionHandler, StragglerDetector
+from repro.runtime.compile_cache import enable_compile_cache
 
 
 def make_mesh_or_none(data: int, model: int):
     n = len(jax.devices())
     if data * model > n:
         return None  # single-device CPU path: no mesh, no rules
-    return jax.make_mesh((data, model), ("data", "model"))
+    return mesh_lib.make_mesh((data, model), ("data", "model"))
 
 
 def train(arch: str, *, smoke: bool = True, steps: int = 100,
@@ -129,6 +130,7 @@ def main(argv=None) -> int:
     p.add_argument("--seed", type=int, default=0)
     p.add_argument("--log-every", type=int, default=10)
     args = p.parse_args(argv)
+    enable_compile_cache()
     # context manager: SIGTERM/SIGINT handlers are restored on exit even
     # if train() raises, so embedding callers keep their own handlers
     with PreemptionHandler() as preempt:

@@ -39,6 +39,12 @@ from repro.dists import (Bernoulli, BernoulliLogits, Categorical, Dirichlet,
 __all__ = ["PaperModel", "build", "MODEL_NAMES"]
 
 _LOG_2PI = math.log(2.0 * math.pi)
+# A float32 matmul on a TPU defaults to one bfloat16 pass, which moves a
+# 10,000-row log-likelihood by far more than float32 rounding. A single
+# ``X @ w`` may run in float32 anyway, but under ``vmap`` over draws or
+# chains it becomes a matrix product on the MXU; the densities below state
+# full float32 precision.
+_F32_MATMUL = jax.lax.Precision.HIGHEST
 
 
 @dataclasses.dataclass
@@ -143,14 +149,15 @@ def logreg(n: int = 10_000, dim: int = 100, seed: int = 2) -> PaperModel:
     def lr(X, y):
         w = sample("w", MvNormalDiag(jnp.zeros(dim), jnp.ones(dim)))
         b = sample("b", Normal(0.0, 3.0))
-        observe("y", BernoulliLogits(X @ w + b), y)
+        observe("y", BernoulliLogits(
+            jnp.matmul(X, w, precision=_F32_MATMUL) + b), y)
 
     Xj, yj = jnp.asarray(X), jnp.asarray(y)
 
     def handwritten(q):
         w, b = q[:dim], q[dim]
         lp = jnp.sum(_norm_lp(w, 0.0, 1.0)) + _norm_lp(b, 0.0, 3.0)
-        logit = Xj @ w + b
+        logit = jnp.matmul(Xj, w, precision=_F32_MATMUL) + b
         lp += jnp.sum(yj * logit - jax.nn.softplus(logit))
         return lp
 
@@ -358,7 +365,8 @@ def lda(V: int = 100, K: int = 5, D: int = 10, avg_len: int = 1_000,
         theta = sample("theta", Dirichlet(alpha))  # (D,K)
         phi = sample("phi", Dirichlet(beta))       # (K,V)
         # collapsed topic assignment: word ~ Categorical(theta[d] @ phi)
-        word_probs = theta[doc_ids] @ phi          # (N,V)
+        word_probs = jnp.matmul(theta[doc_ids], phi,
+                                precision=_F32_MATMUL)  # (N,V)
         observe("w", Categorical(jnp.log(word_probs)), words)
 
     dj, wj = jnp.asarray(doc_ids), jnp.asarray(words)
@@ -378,7 +386,7 @@ def lda(V: int = 100, K: int = 5, D: int = 10, avg_len: int = 1_000,
                     - jnp.sum(jax.scipy.special.gammaln(conc))
                     + jnp.sum(jax.scipy.special.gammaln(jnp.sum(conc, -1))))
         lp += dir_lp(theta, alpha) + dir_lp(phi, beta)
-        word_probs = theta[dj] @ phi
+        word_probs = jnp.matmul(theta[dj], phi, precision=_F32_MATMUL)
         lp += jnp.sum(jnp.log(word_probs[jnp.arange(wj.shape[0]), wj]))
         return lp
 

@@ -18,7 +18,6 @@ Two dispatch implementations:
 """
 from __future__ import annotations
 
-import functools
 import math
 from typing import Any, Dict, Optional
 
@@ -193,15 +192,10 @@ def moe_ffn_ep(params, x, *, top_k: int, capacity_factor: float = 1.25,
                                norm_topk_probs=norm_topk_probs)
         return y.reshape(b, s, D)
 
-    if hasattr(jax, "shard_map"):  # jax >= 0.5
-        smap = functools.partial(jax.shard_map, check_vma=False)
-    else:
-        from jax.experimental.shard_map import shard_map as _shard_map
-        smap = functools.partial(_shard_map, check_rep=False)
-    y = smap(
+    y = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(None, None), P(axis, None, None), x_spec),
-        out_specs=x_spec,
+        out_specs=x_spec, check_vma=False,
     )(params["router"], ew, x)
 
     if "shared" in params:

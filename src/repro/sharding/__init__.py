@@ -59,11 +59,7 @@ class Rules:
             if isinstance(v, str):
                 return v if v in valid else None
             kept = tuple(a for a in v if a in valid)
-            if not kept:
-                return None
-            # normalise 1-tuples to the bare axis name: semantically the
-            # same sharding, but PartitionSpec(('a',)) != PartitionSpec('a')
-            return kept[0] if len(kept) == 1 else kept
+            return kept or None
 
         return Rules({k: ok(v) for k, v in self.mapping.items()}, mesh,
                      self.fsdp)

@@ -90,7 +90,6 @@ def make_sharded_logdensity(model, tvi_linked, plan, *,
     and unsharded densities of the same model never collide.
     """
     import jax
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.core.program import (CompiledProgram, ProgramKey,
@@ -114,10 +113,10 @@ def make_sharded_logdensity(model, tvi_linked, plan, *,
                                    backend=backend)
         return prior + all_reduce_block_sum(lik, plan.data_axis)
 
-    mapped = shard_map(
+    mapped = jax.shard_map(
         local_density, mesh=plan.mesh,
         in_specs=(P(),) + (P(plan.data_axis),) * len(sites),
-        out_specs=P(), check_rep=False)
+        out_specs=P(), check_vma=False)
 
     key = ProgramKey(model_fingerprint(model), "density", tvi_linked.layout,
                      (), backend, (), plan.fingerprint())

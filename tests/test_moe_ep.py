@@ -11,7 +11,8 @@ from repro.nn.common import Initializer
 
 
 def _mesh_and_rules():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     return mesh, sharding.DEFAULT_RULES.with_mesh(mesh)
 
 

@@ -82,40 +82,35 @@ def test_fit_spec_drops_nondivisible():
 
 def test_with_mesh_drops_unknown_axes_and_normalises_1tuples():
     """``Rules.with_mesh``: rules referencing axes the mesh lacks are
-    dropped, and a multi-axis rule that survives with ONE axis collapses
-    to the bare name — PartitionSpec(('a',)) and PartitionSpec('a') mean
-    the same sharding but compare unequal, so specs must be canonical."""
+    dropped; a multi-axis rule that survives with ONE axis resolves to
+    the same PartitionSpec as the bare name."""
     r = sharding.Rules({
-        "batch": ("pod", "data"),   # pod missing -> 1-tuple -> bare "data"
+        "batch": ("pod", "data"),   # pod missing -> ("data",)
         "heads": "model",           # plain string kept verbatim
         "mlp": "tensor",            # unknown string -> dropped to None
         "experts": ("ep", "tp"),    # both unknown -> None
         "seq": None,                # None passes through
         "state": ("data", "model"),  # both valid -> tuple preserved
     }).with_mesh(MESH)
-    assert r.mapping["batch"] == "data"          # NOT ("data",)
-    assert not isinstance(r.mapping["batch"], tuple)
+    assert r.mapping["batch"] == ("data",)
     assert r.mapping["heads"] == "model"
     assert r.mapping["mlp"] is None
     assert r.mapping["experts"] is None
     assert r.mapping["seq"] is None
     assert r.mapping["state"] == ("data", "model")
-    # the canonical form is what makes spec equality (and thus program
-    # cache keys / sharding comparisons) work:
     assert r.spec("batch") == P("data")
-    assert P(("data",)) != P("data")  # the trap the normalisation avoids
     # original Rules object untouched (with_mesh is functional)
     assert r.mesh is MESH
 
 
 def test_with_mesh_of_inference_mesh_axes():
     """DEFAULT_RULES against the inference chains x data mesh: every
-    surviving value is either a bare valid axis name or None."""
+    surviving value resolves to the data axis or to None."""
     m = FakeMesh((2, 4), ("chains", "data"))
     r = sharding.DEFAULT_RULES.with_mesh(m)
     for k, v in r.mapping.items():
-        assert v is None or v == "data", (k, v)
-    assert r.mapping["batch"] == "data"  # ("pod","data") -> "data"
+        assert v is None or P(v) == P("data"), (k, v)
+    assert r.spec("batch") == P("data")  # ("pod","data") -> data
 
 
 def test_rules_for_cell_fsdp_threshold():
