@@ -522,36 +522,65 @@ def run_chains(key, model, kernel, num_samples: int, *, num_warmup: int = 0,
         ``stats`` holds ``logp`` and the kernel's extras (accept_prob,
         diverging, ...); ``health`` carries the ``ChainHealth`` report.
     """
+    from repro.core.program import call_span, program_cache, span
+    from repro.sharding.mesh import ShardedRun
+    with call_span("repro.run_chains", num_chains=num_chains,
+                   num_warmup=num_warmup, num_samples=num_samples):
+        plan = ShardedRun.normalize(mesh)
+        if plan is not None and plan.is_trivial:
+            plan = None  # graceful degradation: one device == no mesh
+        if plan is not None:
+            plan.validate_chains(num_chains)
+
+        if (checkpoint_dir is not None or checkpoint_every is not None
+                or preemption is not None):
+            from repro.infer.driver import run_segmented
+            return run_segmented(
+                key, model, kernel, num_samples, num_warmup=num_warmup,
+                num_chains=num_chains, init_varinfo=init_varinfo,
+                init_jitter=init_jitter, backend=backend, mesh=plan,
+                checkpoint_dir=checkpoint_dir,
+                checkpoint_every=checkpoint_every,
+                checkpoint_keep=checkpoint_keep, preemption=preemption,
+                fallback=fallback)
+
+        cache = program_cache()
+        stats0 = cache.stats()
+        with span("repro.run_chains.setup"):
+            tvi, kern, dim, q0s, chain_keys = setup_chain_driver(
+                key, model, kernel, num_chains=num_chains,
+                init_varinfo=init_varinfo, init_jitter=init_jitter,
+                backend=backend)
+        with span("repro.run_chains.dispatch"):
+            outs = _dispatch(plan, model, tvi, kern, kernel, dim,
+                             num_chains, num_warmup, num_samples,
+                             init_jitter, backend, chain_keys, q0s, cache)
+        with span("repro.run_chains.collect"):
+            qs = outs.pop("q")
+            chain = package_draws(tvi, qs, stats=outs)
+            from repro.infer.driver import health_from_stats
+            chain.health = health_from_stats(
+                chain.stats, num_warmup=num_warmup, num_samples=num_samples,
+                num_chains=num_chains)
+            s1 = cache.stats()
+            h = chain.health
+            h.cache_hits = max(0, s1["hits"] - stats0["hits"])
+            h.cache_misses = max(0, s1["misses"] - stats0["misses"])
+            h.cache_retraces = max(0, s1["retraces"] - stats0["retraces"])
+            h.fingerprint_bytes = (s1["fingerprint_bytes"]
+                                   - stats0["fingerprint_bytes"])
+        return chain
+
+
+def _dispatch(plan, model, tvi, kern, kernel, dim: int, num_chains: int,
+              num_warmup: int, num_samples: int, init_jitter: float,
+              backend: str, chain_keys, q0s, cache):
+    """Look up (or build) the chain program and call it; returns its
+    outputs as the device produced them (dispatch is asynchronous)."""
     import jax
 
-    from repro.sharding.mesh import ShardedRun
-    plan = ShardedRun.normalize(mesh)
-    if plan is not None and plan.is_trivial:
-        plan = None  # graceful degradation: one device == no mesh
-    if plan is not None:
-        plan.validate_chains(num_chains)
-
-    if (checkpoint_dir is not None or checkpoint_every is not None
-            or preemption is not None):
-        from repro.infer.driver import run_segmented
-        return run_segmented(
-            key, model, kernel, num_samples, num_warmup=num_warmup,
-            num_chains=num_chains, init_varinfo=init_varinfo,
-            init_jitter=init_jitter, backend=backend, mesh=plan,
-            checkpoint_dir=checkpoint_dir, checkpoint_every=checkpoint_every,
-            checkpoint_keep=checkpoint_keep, preemption=preemption,
-            fallback=fallback)
-
     from repro.core.program import (CompiledProgram, ProgramKey,
-                                    kernel_fingerprint, model_fingerprint,
-                                    program_cache)
-    cache = program_cache()
-    stats0 = cache.stats()
-
-    tvi, kern, dim, q0s, chain_keys = setup_chain_driver(
-        key, model, kernel, num_chains=num_chains, init_varinfo=init_varinfo,
-        init_jitter=init_jitter, backend=backend)
-
+                                    kernel_fingerprint, model_fingerprint)
     if plan is not None and plan.num_data_shards > 1:
         # chains x data mesh program (likelihood psum inside the density)
         outs = _sharded_chain_outs(
@@ -588,14 +617,4 @@ def run_chains(key, model, kernel, num_samples: int, *, num_warmup: int = 0,
             outs = prog(chain_keys, q0s)
         else:
             outs = jax.jit(jax.vmap(one_chain))(chain_keys, q0s)
-    qs = outs.pop("q")
-    chain = package_draws(tvi, qs, stats=outs)
-    from repro.infer.driver import health_from_stats
-    chain.health = health_from_stats(chain.stats, num_warmup=num_warmup,
-                                     num_samples=num_samples,
-                                     num_chains=num_chains)
-    s1 = cache.stats()
-    chain.health.cache_hits = max(0, s1["hits"] - stats0["hits"])
-    chain.health.cache_misses = max(0, s1["misses"] - stats0["misses"])
-    chain.health.cache_retraces = max(0, s1["retraces"] - stats0["retraces"])
-    return chain
+    return outs

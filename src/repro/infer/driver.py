@@ -81,6 +81,7 @@ class ChainHealth:
     cache_hits: int = 0             # ProgramCache hits during this run
     cache_misses: int = 0           # programs compiled during this run
     cache_retraces: int = 0         # jit traces of cached programs
+    fingerprint_bytes: int = 0      # bound data hashed to key programs
 
     @property
     def completed_samples(self) -> int:
@@ -122,10 +123,12 @@ class ChainHealth:
         if self.resumed_from is not None:
             lines.append(f"  resumed from committed iteration "
                          f"{self.resumed_from}")
-        if self.cache_hits or self.cache_misses or self.cache_retraces:
+        if (self.cache_hits or self.cache_misses or self.cache_retraces
+                or self.fingerprint_bytes):
             lines.append(f"  program cache: {self.cache_hits} hit(s), "
                          f"{self.cache_misses} miss(es), "
-                         f"{self.cache_retraces} retrace(s)")
+                         f"{self.cache_retraces} retrace(s), "
+                         f"{self.fingerprint_bytes} byte(s) fingerprinted")
         return "\n".join(lines)
 
 
@@ -281,13 +284,15 @@ def run_segmented(key, model, sampler, num_samples: int, *,
         plan.validate_chains(num_chains)
 
     from repro.core.program import (ProgramKey, kernel_fingerprint,
-                                    model_fingerprint, program_cache)
+                                    model_fingerprint, program_cache, span)
     cache = program_cache()
     cstats0 = cache.stats()
 
-    tvi, kern, dim, q0s, chain_keys = setup_chain_driver(
-        key, model, sampler, num_chains=num_chains,
-        init_varinfo=init_varinfo, init_jitter=init_jitter, backend=backend)
+    with span("repro.run_chains.setup"):
+        tvi, kern, dim, q0s, chain_keys = setup_chain_driver(
+            key, model, sampler, num_chains=num_chains,
+            init_varinfo=init_varinfo, init_jitter=init_jitter,
+            backend=backend)
 
     # presplit per-draw keys with the SAME derivation as the single-scan
     # driver — slicing a presplit block is what makes segment boundaries
@@ -388,8 +393,9 @@ def run_segmented(key, model, sampler, num_samples: int, *,
                 "cache_retraces": np.zeros((), np.int64)}
 
     # format bumped to /2 when the cache counters joined RunState: a /1
-    # snapshot has a different pytree and is refused by the meta check
-    meta = {"format": "run_chains/2", "num_chains": int(num_chains),
+    # snapshot has a different pytree and is refused by the meta check;
+    # bumped to /3 when NUTS's stat_bufs gained ``n_leapfrog``
+    meta = {"format": "run_chains/3", "num_chains": int(num_chains),
             "num_warmup": int(num_warmup), "num_samples": int(num_samples),
             "dim": int(dim), "sampler": type(sampler).__name__,
             "backend": backend,
@@ -568,5 +574,7 @@ def run_segmented(key, model, sampler, num_samples: int, *,
         checkpoint_dir=checkpoint_dir,
         cache_hits=max(0, cache.stats()["hits"] - cstats0["hits"]),
         cache_misses=int(counters["cache_misses"]),
-        cache_retraces=int(counters["cache_retraces"]))
+        cache_retraces=int(counters["cache_retraces"]),
+        fingerprint_bytes=(cache.stats()["fingerprint_bytes"]
+                           - cstats0["fingerprint_bytes"]))
     return chain

@@ -325,14 +325,16 @@ class HMC:
 
         if use_fused:
             def ld_and_grad(q):
-                return potential_value_and_grad(spec, q)
+                with jax.named_scope("repro.logdensity"):
+                    return potential_value_and_grad(spec, q)
 
             def leapfrog_fn(q, p, grad, eps, n):
                 return fused_leapfrog(spec, q, p, grad, eps, n,
                                       inv_mass=inv_mass)
         else:
             def ld_and_grad(q):
-                return jax.value_and_grad(logdensity)(q)
+                with jax.named_scope("repro.logdensity"):
+                    return jax.value_and_grad(logdensity)(q)
 
             leapfrog_fn = None
 

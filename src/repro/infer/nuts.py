@@ -87,24 +87,36 @@ class NUTS:
                 f"model{why}; use leapfrog='auto' to fall back to autodiff "
                 "gradients")
         if spec is not None and self.leapfrog != "reference":
-            return lambda q: potential_value_and_grad(spec, q)
-        return jax.value_and_grad(logdensity)
+            def value_and_grad(q):
+                return potential_value_and_grad(spec, q)
+        else:
+            value_and_grad = jax.value_and_grad(logdensity)
+
+        def ld_grad(q):
+            with jax.named_scope("repro.logdensity"):
+                return value_and_grad(q)
+
+        return ld_grad
 
     def _build_step(self, ld_grad, dim: int):
         """Build the single compiled NUTS transition.
 
         Returns ``nuts_step(q0, logp0, grad0, eps, key) -> (q, logp, grad,
-        accept_prob, tree_depth, diverging)`` — shared by :meth:`run` and
-        :meth:`make_kernel` so both drivers run identical tree code.
+        accept_prob, tree_depth, diverging, n_leapfrog)`` — shared by
+        :meth:`run` and :meth:`make_kernel` so both drivers run identical
+        tree code. ``n_leapfrog`` is the number of leapfrog steps the
+        tree took: the last subtree may stop early, so it lies between
+        ``2**(tree_depth - 1)`` and ``2**tree_depth - 1``.
         """
 
         def one_leapfrog(q, p, grad, eps, direction):
-            e = eps * direction
-            p = p + 0.5 * e * grad
-            q = q + e * p
-            logp, grad = ld_grad(q)
-            p = p + 0.5 * e * grad
-            return q, p, logp, grad
+            with jax.named_scope("repro.integrator"):
+                e = eps * direction
+                p = p + 0.5 * e * grad
+                q = q + e * p
+                logp, grad = ld_grad(q)
+                p = p + 0.5 * e * grad
+                return q, p, logp, grad
 
         def nuts_step(q0, logp0, grad0, eps, key):
             k_mom, k_dir, k_mult = jax.random.split(key, 3)
@@ -232,7 +244,8 @@ class NUTS:
             out = jax.lax.while_loop(expand_cond, expand_body, init)
             acc_prob = out["sum_acc"] / jnp.maximum(out["n_acc"], 1.0)
             return (out["q_prop"], out["logp_prop"], out["grad_prop"],
-                    acc_prob, out["depth"], out["diverging"])
+                    acc_prob, out["depth"], out["diverging"],
+                    out["n_acc"].astype(jnp.int32))
 
         return nuts_step
 
@@ -242,9 +255,10 @@ class NUTS:
         """Build the pure NUTS :class:`TransitionKernel` for ``run_chains``.
 
         State is ``(q, logp, grad, da_state, eps)``; ``step`` emits
-        ``{"q", "logp", "accept_prob", "tree_depth", "diverging"}`` per
-        draw (``diverging`` = the doubling tree hit an energy error >
-        1000 or NaN and was truncated). Warmup runs dual-averaging on
+        ``{"q", "logp", "accept_prob", "tree_depth", "diverging",
+        "n_leapfrog"}`` per draw (``diverging`` = the doubling tree hit an
+        energy error > 1000 or NaN and was truncated; ``n_leapfrog`` = the
+        leapfrog steps the tree took). Warmup runs dual-averaging on
         the mean subtree acceptance statistic.
         ``spec`` (an optional compiled PotentialSpec) swaps the tree-leaf
         gradient for the fused analytic evaluator; ``spec_reason`` (the
@@ -263,7 +277,8 @@ class NUTS:
         def warm(state, t, key):
             q, logp, grad, da_state, eps = state
             cur = jnp.exp(da_state[0]) if self.adapt_step_size else eps
-            q, logp, grad, acc, _, _ = nuts_step(q, logp, grad, cur, key)
+            q, logp, grad, acc, _, _, _ = nuts_step(q, logp, grad, cur,
+                                                    key)
             if self.adapt_step_size:
                 da_state = da.update(da_state, acc, t)
             return (q, logp, grad, da_state, eps)
@@ -276,10 +291,11 @@ class NUTS:
 
         def step(state, key):
             q, logp, grad, da_state, eps = state
-            q, logp, grad, acc, depth, div = nuts_step(q, logp, grad, eps,
-                                                       key)
+            q, logp, grad, acc, depth, div, n_leapfrog = nuts_step(
+                q, logp, grad, eps, key)
             out = {"q": q, "logp": logp, "accept_prob": acc,
-                   "tree_depth": depth, "diverging": div}
+                   "tree_depth": depth, "diverging": div,
+                   "n_leapfrog": n_leapfrog}
             return (q, logp, grad, da_state, eps), out
 
         use_fused = spec is not None and self.leapfrog != "reference"
@@ -314,7 +330,7 @@ class NUTS:
                 t, k = inp
                 eps = jnp.exp(da_state[0]) if self.adapt_step_size \
                     else jnp.asarray(self.step_size)
-                q, logp, grad, acc, depth, div = nuts_step(q, logp, grad, eps, k)
+                q, logp, grad, acc, _, _, _ = nuts_step(q, logp, grad, eps, k)
                 if self.adapt_step_size:
                     da_state = da.update(da_state, acc, t)
                 return (q, logp, grad, da_state), None
@@ -332,7 +348,8 @@ class NUTS:
 
             def body(carry, k):
                 q, logp, grad = carry
-                q, logp, grad, acc, depth, div = nuts_step(q, logp, grad, eps, k)
+                q, logp, grad, acc, depth, div, _ = nuts_step(q, logp, grad,
+                                                              eps, k)
                 return (q, logp, grad), (q, logp, acc, depth, div)
 
             keys = jax.random.split(jax.random.fold_in(key, 2), num_samples)
