@@ -8,10 +8,15 @@ stages; these kernels keep the elementwise values in VREGs and reduce into
 a VMEM accumulator tile, writing ONE scalar per grid pass — the memory
 traffic drops from 3N reads/writes to N reads.
 
-Layout: inputs are flattened and padded to (R, 128) tiles; the grid walks
+Layout: inputs are flattened and padded to (R, 128) tiles whose geometry
+``ops.tile_geometry`` derives from the true length n (static at trace
+time): ceil(n / 128) rows rounded up to a multiple of 8, cut into
+g = ceil(rows / block_rows) row-blocks of equal height (a multiple of 8,
+block_rows = 256 by default), so a short input is one block of its own
+size and padding stays under 8 rows per block. The grid walks the
 row-blocks sequentially, accumulating partial sums in a VMEM (8, 128)
-accumulator that is reduced to the (1, 1) output on the last step. Padding
-is masked with an iota test against the true length (static at trace time).
+accumulator that is reduced to the (1, 1) output on the last step.
+Padding is masked with an iota test against n.
 
 Three variants cover the paper's benchmark suite:
   normal:          x ~ Normal(mu, sigma)            (gaussian_10k, gdemo, ...)

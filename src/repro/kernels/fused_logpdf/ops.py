@@ -23,7 +23,7 @@ import jax.numpy as jnp
 from repro.kernels.fused_logpdf import kernel as K
 from repro.kernels.fused_logpdf import ref
 
-__all__ = ["normal_logpdf_sum", "std_normal_logpdf_sum",
+__all__ = ["normal_logpdf_sum", "std_normal_logpdf_sum", "tile_geometry",
            "bernoulli_logits_logpmf_sum", "categorical_logits_logpmf_sum",
            "gamma_unnorm_logpdf_sum", "beta_unnorm_logpdf_sum",
            "student_t_unnorm_logpdf_sum", "mvnormal_prec_quadform_sum",
@@ -52,19 +52,37 @@ def _auto_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def _to_tiles(x, block_rows: int, pad_value: float = 0.0):
-    """Flatten to 1-D, pad to (rows, 128) with rows % block_rows == 0.
+def tile_geometry(n: int, block_rows: int,
+                  per_row: int = K.LANE) -> Tuple[int, int]:
+    """Row geometry ``(rows, br)`` of a fused reduction over ``n`` elements.
+
+    The elements fill ``ceil(n / per_row)`` rows, rounded up to a whole
+    f32 ``(8, 128)`` tile. Those rows are cut into ``g = ceil(rows /
+    block_rows)`` grid steps of ``br`` rows each, ``br`` a multiple of 8
+    no larger than ``block_rows``, and padded to ``rows = g * br``. A short
+    input is one block of its own size, a long one keeps blocks of up to
+    ``block_rows`` rows, and the padding stays under 8 rows per block.
+    ``n`` is static (a shape), so the geometry is fixed at trace time.
+    """
+    sub = K.SUB
+    rows = max(sub, -(-n // per_row))
+    rows = -(-rows // sub) * sub
+    g = -(-rows // max(sub, block_rows // sub * sub))
+    br = -(-rows // (g * sub)) * sub
+    return g * br, br
+
+
+def _to_tiles(x, rows: int, pad_value: float = 0.0):
+    """Flatten to 1-D and pad to ``(rows, 128)``, rows from ``tile_geometry``.
 
     ``pad_value`` picks the fill so padding slots stay finite through the
     kernel's elementwise math (e.g. 1.0 for a log() input) — padded lanes
     are masked out of the reduction regardless.
     """
     flat = jnp.ravel(x)
-    n = flat.shape[0]
-    per_block = block_rows * K.LANE
-    n_pad = ((n + per_block - 1) // per_block) * per_block
-    flat = jnp.pad(flat, (0, n_pad - n), constant_values=pad_value)
-    return flat.reshape(-1, K.LANE), n
+    flat = jnp.pad(flat, (0, rows * K.LANE - flat.shape[0]),
+                   constant_values=pad_value)
+    return flat.reshape(rows, K.LANE)
 
 
 def std_normal_logpdf_sum(z, *, block_rows: int = 256,
@@ -115,9 +133,9 @@ _std_normal_sum_vjp.defvjp(_std_normal_sum_fwd, _std_normal_sum_bwd)
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
 def _std_normal_sum_impl(z, *, block_rows: int, interpret: bool):
-    z2, n = _to_tiles(z, block_rows)
-    br = min(block_rows, z2.shape[0])
-    return K.std_normal_sum_2d(z2, n, br, interpret)
+    n = z.size
+    rows, br = tile_geometry(n, block_rows)
+    return K.std_normal_sum_2d(_to_tiles(z, rows), n, br, interpret)
 
 
 def normal_logpdf_sum(x, loc, scale, *, block_rows: int = 256,
@@ -177,13 +195,12 @@ _normal_sum_vjp.defvjp(_normal_sum_fwd, _normal_sum_bwd)
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
 def _normal_sum_impl(x, mu, sig, *, block_rows: int, interpret: bool):
-    x2, n = _to_tiles(x, block_rows)
-    mu2, _ = _to_tiles(mu, block_rows)
+    n = x.size
+    rows, br = tile_geometry(n, block_rows)
     # pad sigma with 1s: log(sig)=0 on padding (masked anyway; avoids log 0)
-    sig2, _ = _to_tiles(sig - 1.0, block_rows)
-    sig2 = sig2 + 1.0
-    br = min(block_rows, x2.shape[0])
-    return K.normal_sum_2d(x2, mu2, sig2, n, br, interpret)
+    sig2 = _to_tiles(sig - 1.0, rows) + 1.0
+    return K.normal_sum_2d(_to_tiles(x, rows), _to_tiles(mu, rows), sig2,
+                           n, br, interpret)
 
 
 def bernoulli_logits_logpmf_sum(logits, y, *, block_rows: int = 256,
@@ -234,10 +251,10 @@ _bern_sum_vjp.defvjp(_bern_sum_fwd, _bern_sum_bwd)
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
 def _bern_sum_impl(logits, y, *, block_rows: int, interpret: bool):
-    l2, n = _to_tiles(logits, block_rows)
-    y2, _ = _to_tiles(y, block_rows)
-    br = min(block_rows, l2.shape[0])
-    return K.bernoulli_logit_sum_2d(l2, y2, n, br, interpret)
+    n = logits.size
+    rows, br = tile_geometry(n, block_rows)
+    return K.bernoulli_logit_sum_2d(_to_tiles(logits, rows),
+                                    _to_tiles(y, rows), n, br, interpret)
 
 
 def categorical_logits_logpmf_sum(logits, labels, *, block_rows: int = 128,
@@ -334,12 +351,12 @@ _gamma_sum_vjp.defvjp(_gamma_sum_fwd, _gamma_sum_bwd)
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
 def _gamma_sum_impl(x, am1, rate, *, block_rows: int, interpret: bool):
+    n = x.size
+    rows, br = tile_geometry(n, block_rows)
     # pad x with 1s: log(1)=0 keeps the padded lanes NaN-free
-    x2, n = _to_tiles(x, block_rows, pad_value=1.0)
-    am12, _ = _to_tiles(am1, block_rows)
-    rate2, _ = _to_tiles(rate, block_rows)
-    br = min(block_rows, x2.shape[0])
-    return K.gamma_sum_2d(x2, am12, rate2, n, br, interpret)
+    return K.gamma_sum_2d(_to_tiles(x, rows, pad_value=1.0),
+                          _to_tiles(am1, rows), _to_tiles(rate, rows),
+                          n, br, interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -384,12 +401,12 @@ _beta_sum_vjp.defvjp(_beta_sum_fwd, _beta_sum_bwd)
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
 def _beta_sum_impl(x, am1, bm1, *, block_rows: int, interpret: bool):
+    n = x.size
+    rows, br = tile_geometry(n, block_rows)
     # pad x with 0.5: both log(x) and log1p(-x) stay finite on padding
-    x2, n = _to_tiles(x, block_rows, pad_value=0.5)
-    am12, _ = _to_tiles(am1, block_rows)
-    bm12, _ = _to_tiles(bm1, block_rows)
-    br = min(block_rows, x2.shape[0])
-    return K.beta_sum_2d(x2, am12, bm12, n, br, interpret)
+    return K.beta_sum_2d(_to_tiles(x, rows, pad_value=0.5),
+                         _to_tiles(am1, rows), _to_tiles(bm1, rows),
+                         n, br, interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -437,11 +454,12 @@ _student_t_sum_vjp.defvjp(_student_t_sum_fwd, _student_t_sum_bwd)
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
 def _student_t_sum_impl(z, df, *, block_rows: int, interpret: bool):
-    z2, n = _to_tiles(z, block_rows)
+    n = z.size
+    rows, br = tile_geometry(n, block_rows)
     # pad df with 1s: log1p(z^2/df) stays finite on padding
-    df2, _ = _to_tiles(df, block_rows, pad_value=1.0)
-    br = min(block_rows, z2.shape[0])
-    return K.student_t_sum_2d(z2, df2, n, br, interpret)
+    return K.student_t_sum_2d(_to_tiles(z, rows),
+                              _to_tiles(df, rows, pad_value=1.0),
+                              n, br, interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -496,8 +514,7 @@ _mvn_quad_vjp.defvjp(_mvn_quad_fwd, _mvn_quad_bwd)
 def _mvn_quad_impl(xc, prec, *, block_rows: int, interpret: bool):
     n, d = xc.shape
     dp = ((d + K.LANE - 1) // K.LANE) * K.LANE
-    br = min(block_rows, max(K.SUB, ((n + K.SUB - 1) // K.SUB) * K.SUB))
-    n_pad = ((n + br - 1) // br) * br
+    n_pad, br = tile_geometry(n, block_rows, per_row=1)
     # zero padding: padded rows/cols contribute exactly 0 to the quadform
     xc2 = jnp.pad(xc, ((0, n_pad - n), (0, dp - d)))
     prec2 = jnp.pad(prec, ((0, dp - d), (0, dp - d)))
@@ -627,8 +644,7 @@ def _cat_sum_impl(logits, labels, *, block_rows: int, interpret: bool):
     n, C = logits.shape
     labels = labels.reshape(-1, 1)
     cp = ((C + K.LANE - 1) // K.LANE) * K.LANE
-    br = min(block_rows, max(K.SUB, ((n + K.SUB - 1) // K.SUB) * K.SUB))
-    n_pad = ((n + br - 1) // br) * br
+    n_pad, br = tile_geometry(n, block_rows, per_row=1)
     logits = jnp.pad(logits, ((0, n_pad - n), (0, cp - C)))
     labels = jnp.pad(labels, ((0, n_pad - n), (0, 0)))
     return K.categorical_sum_2d(logits, labels, n, C, br, interpret)

@@ -125,3 +125,21 @@ def test_site_block_sum_value_and_grad(family, one_chip):
     text = _compile_text(value_and_grad, *_FAMILIES[family],
                          sharding=one_chip)
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("n", [101, 10_000, 40_000])
+@pytest.mark.parametrize("family", ["bernoulli_logits", "std_normal"])
+def test_site_block_sum_vmapped_lengths(family, n, one_chip):
+    # the chain programs' path: one density per chain under vmap, at the
+    # logreg prior's and likelihood's lengths and one past a 256-row block
+    shapes = _FAMILIES[family]
+
+    def value_and_grad(first, *rest):
+        return jax.value_and_grad(
+            lambda a: site_block_sum(family, [(a,) + rest], use_pallas=True,
+                                     interpret=False))(first)
+
+    text = _compile_text(jax.vmap(value_and_grad),
+                         *[((CHAINS, n), d) for _, d in shapes],
+                         sharding=one_chip)
+    assert "tpu_custom_call" in text
