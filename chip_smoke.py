@@ -258,8 +258,11 @@ def phase_fused_logjoint(n: int = 10_000, dim: int = 100, chains: int = 8,
     require(kernel_in_hlo(hlo, "fused_bernoulli_logpdf"),
             "the logreg density program does not run the Pallas "
             "fused_bernoulli_logpdf kernel")
+    require(not kernel_in_hlo(hlo, "fused_std_normal_logpdf"),
+            "the logreg prior's 101-coordinate block launches the Pallas "
+            "fused_std_normal_logpdf kernel; one (8, 128) tile is XLA's")
     print("logreg density program: fused_bernoulli_logpdf is a "
-          "tpu_custom_call", flush=True)
+          "tpu_custom_call, the prior is XLA's", flush=True)
 
     with jax.default_device(_cpu()):
         pm_cpu = paper_suite.logreg(n=n, dim=dim)

@@ -336,7 +336,11 @@ class ProgramCache:
     def stats(self) -> Dict[str, int]:
         """Aggregate counters, including per-program trace accounting.
         ``fingerprint_bytes`` is process-wide: the bytes of bound data
-        hashed to key programs, which ``clear()`` leaves as they are."""
+        hashed to key programs, which ``clear()`` leaves as they are. So
+        are ``fused_blocks_xla`` and ``fused_blocks_pallas``: the fused
+        log-density's site blocks traced onto XLA's own reduction and onto
+        a Pallas kernel (``kernels.fused_logpdf.ops.pallas_pays``)."""
+        from repro.kernels.fused_logpdf.ops import block_path_counts
         progs = [v for v in self._entries.values()
                  if isinstance(v, CompiledProgram)]
         return {
@@ -347,6 +351,7 @@ class ProgramCache:
             "retraces": sum(p.retraces for p in progs),
             "calls": sum(p.calls for p in progs),
             "fingerprint_bytes": _FINGERPRINT_BYTES,
+            **block_path_counts(),
         }
 
 

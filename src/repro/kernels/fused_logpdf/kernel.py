@@ -18,6 +18,15 @@ row-blocks sequentially, accumulating partial sums in a VMEM (8, 128)
 accumulator that is reduced to the (1, 1) output on the last step.
 Padding is masked with an iota test against n.
 
+Where the kernels run: ``ops.site_block_sum`` launches them on TPU only
+for blocks longer than one (8, 128) f32 tile, ``ops.ONE_TILE`` = 1,024
+elements per call (``ops.pallas_pays``). A block that fits one tile has
+nothing to stream, while a launch costs a fixed time per grid step (one
+step per chain under ``vmap``) and its custom call keeps XLA from fusing
+the pad to tiles and the sum with the ops that produce the block; such
+blocks go to the ``ref.py`` oracle, which XLA fuses. ``use_pallas=True``
+still forces a kernel at any length.
+
 Three variants cover the paper's benchmark suite:
   normal:          x ~ Normal(mu, sigma)            (gaussian_10k, gdemo, ...)
   bernoulli_logit: y ~ BernoulliLogits(l)           (logreg)

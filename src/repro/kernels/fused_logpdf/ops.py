@@ -8,9 +8,10 @@ Two call surfaces:
 * ``site_block_sum`` — the flat-buffer log-joint hot path: ALL same-family
   tilde sites of one model evaluation, pre-flattened into segments by the
   fused evaluators, summed in a single launch. On TPU this is the Pallas
-  kernel; elsewhere it falls back to the pure-jnp oracle in ``ref.py``
-  (mathematically identical, still one fused XLA reduction over the
-  concatenated block).
+  kernel for blocks longer than one (8, 128) tile (``pallas_pays``);
+  shorter blocks, and every block elsewhere, take the pure-jnp oracle in
+  ``ref.py`` (mathematically identical, one XLA reduction that fuses with
+  the ops around it).
 """
 from __future__ import annotations
 
@@ -27,7 +28,8 @@ __all__ = ["normal_logpdf_sum", "std_normal_logpdf_sum", "tile_geometry",
            "bernoulli_logits_logpmf_sum", "categorical_logits_logpmf_sum",
            "gamma_unnorm_logpdf_sum", "beta_unnorm_logpdf_sum",
            "student_t_unnorm_logpdf_sum", "mvnormal_prec_quadform_sum",
-           "site_block_sum", "all_reduce_block_sum", "SITE_BLOCK_FAMILIES"]
+           "site_block_sum", "all_reduce_block_sum", "SITE_BLOCK_FAMILIES",
+           "ONE_TILE", "pallas_pays", "block_path_counts"]
 
 
 def all_reduce_block_sum(total: jax.Array, axis_name=None) -> jax.Array:
@@ -48,8 +50,12 @@ def all_reduce_block_sum(total: jax.Array, axis_name=None) -> jax.Array:
     return jax.lax.psum(total, axis_name)
 
 
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
 def _auto_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    return not _on_tpu()
 
 
 def tile_geometry(n: int, block_rows: int,
@@ -527,6 +533,44 @@ def _mvn_quad_impl(xc, prec, *, block_rows: int, interpret: bool):
 SITE_BLOCK_FAMILIES = ("std_normal", "normal", "bernoulli_logits",
                        "categorical_logits", "gamma", "beta", "student_t",
                        "mvnormal_prec")
+# families laid out 128 elements to a row by tile_geometry(n, block_rows)
+_LANE_FAMILIES = ("std_normal", "normal", "bernoulli_logits", "gamma",
+                  "beta", "student_t")
+# elements of one f32 (8, 128) tile: the most a block may hold and still
+# be reduced by XLA rather than a Pallas launch
+ONE_TILE = K.SUB * K.LANE
+
+# site blocks routed to each path, process-wide, counted at trace time
+# (cache_stats()'s ``fused_blocks_xla`` and ``fused_blocks_pallas``)
+_BLOCK_PATHS = {"fused_blocks_xla": 0, "fused_blocks_pallas": 0}
+
+
+def block_path_counts():
+    """Site blocks that ``site_block_sum`` has routed to XLA's reduction
+    and to a Pallas kernel in this process, counted once per trace."""
+    return dict(_BLOCK_PATHS)
+
+
+def pallas_pays(family: str, n: int) -> bool:
+    """Whether a Pallas launch, not XLA, should sum a ``family`` block of
+    ``n`` elements per call on a TPU.
+
+    A lane-laid family whose block fits one (8, 128) f32 tile
+    (``n <= ONE_TILE``) leaves the kernel nothing to stream: it would pay
+    its launch, its fixed cost per grid step and the pad to whole tiles
+    for data one tile holds, while XLA folds the sum into the fusion that
+    produces the block. Longer blocks keep the kernel. Categorical and
+    dense-MvNormal blocks are laid out per row and always keep it.
+    """
+    return family not in _LANE_FAMILIES or n > ONE_TILE
+
+
+def _route(family: str, n: int, use_pallas: Optional[bool]) -> bool:
+    if use_pallas is None:
+        use_pallas = _on_tpu() and pallas_pays(family, n)
+    _BLOCK_PATHS["fused_blocks_pallas" if use_pallas
+                 else "fused_blocks_xla"] += 1
+    return use_pallas
 
 
 def site_block_sum(family: str, segments: Sequence[Tuple],
@@ -567,9 +611,15 @@ def site_block_sum(family: str, segments: Sequence[Tuple],
     segments : sequence of tuples of jax.Array
         Per-site flattened parameter/value blocks as above.
     use_pallas : bool, optional
-        Force (``True``) or forbid (``False``) the Pallas kernel; default
-        auto-selects it on TPU and uses the ``ref.py`` jnp oracle elsewhere
-        (interpret-mode Pallas is for validation, not speed).
+        Force (``True``) or forbid (``False``) the Pallas kernel. The
+        default picks it on TPU where ``pallas_pays(family, n)``, ``n``
+        the concatenated block's static per-call length (one chain's under
+        ``vmap``, one device's under a data-sharded mesh): a lane-laid
+        block of at most ``ONE_TILE`` (1,024) elements is summed by the
+        ``ref.py`` jnp oracle, which XLA fuses with its neighbours, and
+        so is every block off the TPU (interpret-mode Pallas is for
+        validation, not speed). Each choice is counted
+        (``block_path_counts``).
     interpret : bool, optional
         Passed through to the Pallas wrappers when ``use_pallas``.
 
@@ -585,9 +635,8 @@ def site_block_sum(family: str, segments: Sequence[Tuple],
                          f"expected one of {SITE_BLOCK_FAMILIES}")
     if not segments:
         return jnp.zeros((), jnp.float32)
-    if use_pallas is None:
-        use_pallas = jax.default_backend() == "tpu"
     if family == "mvnormal_prec":
+        use_pallas = _route(family, 0, use_pallas)
         # each segment carries its own precision matrix: one launch per site
         total = jnp.zeros((), jnp.float32)
         for xc, prec in segments:
@@ -602,6 +651,7 @@ def site_block_sum(family: str, segments: Sequence[Tuple],
     else:
         cols = tuple(jnp.concatenate(parts, axis=0)
                      for parts in zip(*segments))
+    use_pallas = _route(family, cols[0].size, use_pallas)
     if family == "std_normal":
         (z,) = cols
         if use_pallas:

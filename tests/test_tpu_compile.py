@@ -18,6 +18,7 @@ import jax.numpy as jnp
 from repro.kernels.fused_leapfrog import (fused_leapfrog,
                                           potential_value_and_grad)
 from repro.kernels.fused_leapfrog.spec import N_OPS, PotentialSpec
+from repro.kernels.fused_logpdf import ops
 from repro.kernels.fused_logpdf.ops import site_block_sum
 
 DIM = 10_000        # gaussian_10k, logreg rows
@@ -143,3 +144,25 @@ def test_site_block_sum_vmapped_lengths(family, n, one_chip):
                          *[((CHAINS, n), d) for _, d in shapes],
                          sharding=one_chip)
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("family, n, kernel, present", [
+    ("std_normal", 101, "fused_std_normal_logpdf", False),
+    ("bernoulli_logits", 10_000, "fused_bernoulli_logpdf", True),
+])
+def test_site_block_sum_auto_path(family, n, kernel, present, one_chip,
+                                  monkeypatch):
+    # the path left to the rule, as the fused evaluators call it: the
+    # logreg prior's one-tile block is XLA's, its logits keep the kernel
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    shapes = _FAMILIES[family]
+
+    def value_and_grad(first, *rest):
+        return jax.value_and_grad(
+            lambda a: site_block_sum(family, [(a,) + rest],
+                                     interpret=False))(first)
+
+    text = _compile_text(jax.vmap(value_and_grad),
+                         *[((CHAINS, n), d) for _, d in shapes],
+                         sharding=one_chip)
+    assert (kernel in text) == present
